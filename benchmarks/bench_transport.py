@@ -142,6 +142,9 @@ def _two_process_row(art, requests: int = 32) -> dict:
     env["PYTHONPATH"] = os.path.join(root, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     env["PYTHONUNBUFFERED"] = "1"
+    # the children test the transport, not the device — and this parent
+    # already touched JAX, so it holds the chip a child would wait on
+    env["JAX_PLATFORMS"] = "cpu"
     leader_npy = os.path.join(CM.RESULTS, "transport_leader_labels.npy")
     follower_npy = os.path.join(CM.RESULTS, "transport_follower_labels.npy")
     art_path = os.path.abspath(CM.ART_PATH)

@@ -36,6 +36,8 @@ def main(argv=None) -> None:
                     help="run a single bench (e.g. sparsity)")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_board_emu, bench_conformance,
                             bench_crossplatform, bench_event_pipeline,
                             bench_repeatability, bench_resources,

@@ -97,14 +97,15 @@ def _build_bundle(prog: LoweredProgram, mode: str, kernel: str) -> dict:
 
     # ------------------------------------------------------------ event mode
     def event_currents(ids: jnp.ndarray) -> jnp.ndarray:
-        """(T, E_max) event ids -> (T, N_pad) int32 currents via row gather."""
+        """(B, T, E_max) event ids -> (B, T, N_pad) int32 currents via row
+        gather."""
         if kernel == "pallas":
             from repro.kernels.event_accum import ops as ea
             return ea.event_accum(ids, w_padded)
         safe = jnp.maximum(ids, 0)
-        rows = w_padded[safe].astype(jnp.int32)                 # (T, E, N_pad)
+        rows = w_padded[safe].astype(jnp.int32)              # (B, T, E, N_pad)
         mask = (ids != PAD)[..., None]
-        return jnp.sum(jnp.where(mask, rows, 0), axis=1)
+        return jnp.sum(jnp.where(mask, rows, 0), axis=-2)
 
     def forward_event(ids: jnp.ndarray, count: jnp.ndarray) -> SNNOutput:
         """ids: (B, T, E_max), count: (B, T).
@@ -119,17 +120,10 @@ def _build_bundle(prog: LoweredProgram, mode: str, kernel: str) -> dict:
             v_l = res.v_final[..., :n_out]
             steps = jnp.full(labels.shape, T, jnp.int32)
             return SNNOutput(labels, first_l, v_l, steps)
-        currents = jax.vmap(event_currents)(ids)                # (B, T, N_pad)
+        currents = event_currents(ids)                          # (B, T, N_pad)
         res = lif(jnp.moveaxis(currents, 1, 0))
         labels, first_l, v_l = decode_padded(res.first_spike, res.v_final)
         steps = jnp.full(labels.shape, T, jnp.int32)
-        return SNNOutput(labels, first_l, v_l, steps)
-
-    def forward_event_one_early_exit(ids: jnp.ndarray) -> SNNOutput:
-        """ids: (T, E_max), single example, stop at first output spike."""
-        currents = event_currents(ids)                          # (T, N_pad)
-        res, steps = lif_scan_early_exit(currents, thr_padded, leak_shift, T)
-        labels, first_l, v_l = decode_padded(res.first_spike, res.v_final)
         return SNNOutput(labels, first_l, v_l, steps)
 
     def forward_event_latency(ids: jnp.ndarray,
@@ -139,9 +133,12 @@ def _build_bundle(prog: LoweredProgram, mode: str, kernel: str) -> dict:
             from repro.kernels.fused_event_lif import ops as fused
             res, steps = fused.fused_event_lif_early_exit(
                 ids, count, w_padded, thr_padded, leak_shift)
-            labels, first_l, v_l = decode_padded(res.first_spike, res.v_final)
-            return SNNOutput(labels, first_l, v_l, steps)
-        return jax.vmap(forward_event_one_early_exit)(ids)
+        else:
+            res, steps = jax.vmap(
+                lambda cur: lif_scan_early_exit(cur, thr_padded, leak_shift,
+                                                T))(event_currents(ids))
+        labels, first_l, v_l = decode_padded(res.first_spike, res.v_final)
+        return SNNOutput(labels, first_l, v_l, steps)
 
     if mode == "batch":
         return {"batch": jax.jit(forward_batch)}

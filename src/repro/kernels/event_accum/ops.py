@@ -11,5 +11,5 @@ from repro.kernels.event_accum.kernel import event_accum_kernel
 
 @jax.jit
 def event_accum(ids: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    """ids (T, E_max) int32, w (N_in, N_pad) int8 -> (T, N_pad) int32."""
+    """ids (B, T, E_max) int32, w (N_in, N_pad) int8 -> (B, T, N_pad) int32."""
     return event_accum_kernel(ids, w, interpret=use_interpret())

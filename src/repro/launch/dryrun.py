@@ -178,8 +178,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t_compile = time.perf_counter() - t0 - t_lower
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # jax < 0.5 returns [dict]
-            cost = cost[0]
         print(mem)     # proves it fits
         print({k: cost[k] for k in ("flops", "bytes accessed") if k in cost})
         hlo = compiled.as_text()

@@ -25,15 +25,10 @@ import os
 import time
 
 import jax
+from jax.sharding import AxisType
 
 
 def build_mesh(shape, axes):
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        # jax < 0.5: make_mesh has no axis_types kwarg and axes default to
-        # the same auto-sharding behavior AxisType.Auto selects
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import get_config, reduced as make_reduced
 from repro.models.model import LM
 from repro.serving.engine import ServeEngine
@@ -96,6 +97,7 @@ def main():
                     help="save served labels to this .npy (the two-process "
                          "bit-exactness gate compares them)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.snn_artifact:
         serve_snn(args)

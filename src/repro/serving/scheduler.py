@@ -759,17 +759,17 @@ class ServingScheduler:
             errs = errs + self._canary_errors(lane)
         return errs
 
-    def _warm_errors(self, lane: _Lane) -> list[str]:
+    def _warm(self, lane: _Lane) -> None:
         """Prime the lane's compiled programs with a zero probe batch BEFORE
         it enters service — the watchdog must never mistake first-serve
         compilation for a hang (a lane is 'ready' only once programmed, as a
-        bitstream load would be). A warmup crash is a commissioning fault."""
-        try:
-            lane.serve(np.zeros((self.max_batch, self.n_in), np.float32), 0,
-                       probe=True)
-            return []
-        except Exception as e:  # noqa: BLE001 — failed warmup = failed lane
-            return [f"lane warmup failed: {type(e).__name__}: {e}"]
+        bitstream load would be). The probe does not advance the fault
+        injector's batch clock, so no injected fault explains a probe that
+        raises: the program itself does not run (e.g. a kernel the device's
+        compiler refuses). That propagates — a lane must never hide it by
+        degrading to another datapath."""
+        lane.serve(np.zeros((self.max_batch, self.n_in), np.float32), 0,
+                   probe=True)
 
     # -------------------------------------------------------------- recovery
     def _transition(self, lane: _Lane, to: str, reason: str) -> None:
@@ -788,9 +788,9 @@ class ServingScheduler:
         plan = self.plan.for_lane(lane_id) if self.plan is not None else None
         lane = _Lane(lane_id, self.art, self.spec, self.kernel,
                      self.latency_mode, plan, program=self.program)
-        errs = self._warm_errors(lane)
-        if not errs and self.resilience.startup_checks:
-            errs = self._startup_errors(lane)
+        self._warm(lane)
+        errs = (self._startup_errors(lane) if self.resilience.startup_checks
+                else [])
         if not errs:
             return lane
         t0 = time.perf_counter()
@@ -801,9 +801,9 @@ class ServingScheduler:
                       program=self.program)
         fresh.fault_count = 1
         fresh.restarts = 1
-        errs = self._warm_errors(fresh)
-        if not errs and self.resilience.startup_checks:
-            errs = self._startup_errors(fresh)
+        self._warm(fresh)
+        errs = (self._startup_errors(fresh) if self.resilience.startup_checks
+                else [])
         if not errs:
             self.metrics.inc("lane_restarts")
             self.metrics.inc("recoveries")
@@ -877,8 +877,8 @@ class ServingScheduler:
                           self.latency_mode,
                           lane.plan.after_scrub() if lane.plan is not None
                           else None, program=self.program)
-            errs = self._warm_errors(fresh)
-            if not errs and res.startup_checks:
+            self._warm(fresh)
+            if res.startup_checks:
                 errs = self._startup_errors(fresh)
         except Exception as e:  # noqa: BLE001 — a failed rebuild quarantines
             errs = [f"lane rebuild failed: {type(e).__name__}: {e}"]
@@ -986,8 +986,8 @@ class ServingScheduler:
                           self.latency_mode,
                           lane.plan.after_scrub() if lane.plan is not None
                           else None, program=self.program)
-            errs = self._warm_errors(fresh)
-            if not errs and self.resilience.startup_checks:
+            self._warm(fresh)
+            if self.resilience.startup_checks:
                 errs = self._startup_errors(fresh)
         except Exception as e:  # noqa: BLE001
             errs = [f"lane rebuild failed: {type(e).__name__}: {e}"]

@@ -2,7 +2,8 @@
 
 ``DivergentRuntime`` wraps the software reference and silently flips one
 label and one first-spike time — the exact drift the agreement harness and
-the conformance oracles exist to catch. ``registered_family`` temporarily
+the conformance oracles exist to catch. ``BrokenRuntime`` raises on every
+forward, as a program the device's compiler refuses would. ``registered_family`` temporarily
 installs a factory in ``runtimes._REGISTRY`` and guarantees cleanup, so a
 test cannot leak a fake family into the rest of the suite (which would fail
 the registry-consistency oracle everywhere else).
@@ -44,4 +45,18 @@ def registered_family(name: str, factory):
 @contextlib.contextmanager
 def divergent_family(name: str = "divergent"):
     with registered_family(name, lambda art, opts, **kw: DivergentRuntime(art)):
+        yield
+
+
+class BrokenRuntime:
+    def __init__(self, art):
+        self.program = art
+
+    def forward(self, images):
+        raise RuntimeError("program does not run on this device")
+
+
+@contextlib.contextmanager
+def broken_family(name: str = "broken"):
+    with registered_family(name, lambda art, opts, **kw: BrokenRuntime(art)):
         yield

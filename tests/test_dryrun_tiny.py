@@ -46,8 +46,6 @@ with mesh:
     compiled = step.lower(pspec, opt_spec, batch).compile()
     hlo = compiled.as_text()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax < 0.5 returns [dict]
-        cost = cost[0]
     mem = compiled.memory_analysis()
 coll = HP.collective_bytes_scaled(hlo)
 out["train"] = {"flops": float(cost.get("flops", 0)),

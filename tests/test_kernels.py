@@ -66,14 +66,14 @@ def test_event_accum_shapes(T, E, K, N):
     rng = np.random.RandomState(T * E)
     ids = jnp.asarray(rng.randint(-1, K, (T, E)), jnp.int32)
     w = jnp.asarray(rng.randint(-127, 128, (K, N)), jnp.int8)
-    assert np.array_equal(np.asarray(event_accum(ids, w)),
+    assert np.array_equal(np.asarray(event_accum(ids[None], w)[0]),
                           np.asarray(event_accum_ref(ids, w)))
 
 
 def test_event_accum_all_padding_is_zero():
     w = jnp.asarray(np.random.RandomState(0).randint(-127, 128, (50, 128)),
                     jnp.int8)
-    ids = jnp.full((4, 16), -1, jnp.int32)
+    ids = jnp.full((2, 4, 16), -1, jnp.int32)
     assert np.all(np.asarray(event_accum(ids, w)) == 0)
 
 

@@ -13,6 +13,8 @@ from repro.core.artifact import Artifact
 from repro.core.reference import SNNReference
 from repro.serving.scheduler import ServingError, ServingScheduler
 
+from _fakes import broken_family
+
 
 def _tiny_emax_artifact(art: Artifact, e_max: int = 8) -> Artifact:
     clone = Artifact(copy.deepcopy(art.meta), dict(art.arrays))
@@ -291,3 +293,20 @@ def test_stats_snapshot_consistent_under_concurrent_chaos(trained_artifact):
     assert sorted(done) == sorted(submitted)
     assert st["images_out"] == n and st["lane_faults"] >= 1
     assert all(r.error is None for r in done.values())
+
+
+@pytest.mark.parametrize("workers", [0, 1])
+def test_lane_whose_warmup_raises_fails_construction(trained_artifact,
+                                                      monkeypatch, workers):
+    """No fault plan, so nothing injected can explain a warm-up probe that
+    raises: the program does not run. Construction must raise that error —
+    never quarantine the lane and quietly serve the dense path instead."""
+    art, _, _ = trained_artifact
+    degraded = []
+    monkeypatch.setattr(ServingScheduler, "_degrade",
+                        lambda self, lane: degraded.append(lane.lane_id))
+    with broken_family():
+        with pytest.raises(RuntimeError, match="does not run on this device"):
+            ServingScheduler(art, spec="broken", workers=workers,
+                             max_batch=4)
+    assert degraded == []

@@ -1,0 +1,103 @@
+"""Compile rehearsal: each Pallas kernel of the serving path, at the deployed
+widths, compiled for a described TPU v5e chip with no chip attached.
+
+Interpret mode (every other kernel test) cannot see the TPU's tiling rules;
+the TPU compiler can, and refuses what the chip would refuse. The deployed
+widths: B = 64 (``SNNServeEngine``'s default ``max_batch``), T = 32,
+N_in = 784, N_pad = 256, E_max = 128, 150 outputs in 10 groups of 15.
+
+The topology is described inside a module fixture (never at import), so
+only the pytest worker that runs this file loads the TPU library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+B, T, N_IN, N_PAD, E_MAX, G, P = 64, 32, 784, 256, 128, 10, 15
+LEAK = 4
+I8, I32 = jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        if "TPU_LOG_DIR" not in os.environ:
+            mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """Compiles for a described chip cannot be read back from the
+    persistent cache; keep them out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _case(name):
+    """(kernel callable, [(shape, dtype)...]) at the deployed widths."""
+    from repro.kernels.event_accum.kernel import event_accum_kernel
+    from repro.kernels.fused_event_lif import kernel as fk
+    from repro.kernels.lif.kernel import lif_fused_kernel
+    from repro.kernels.spike_matmul.kernel import spike_matmul_kernel
+    from repro.kernels.ttfs_decode.kernel import ttfs_decode_kernel
+    events = [((B, T, E_MAX), I32), ((B, T), I32), ((N_IN, N_PAD), I8),
+              ((N_PAD,), I32)]
+    k_pad = 896                              # N_in padded to the MXU tile
+    return {
+        "fused_event_lif": (
+            lambda *a: fk.fused_event_lif_kernel(*a, LEAK, interpret=False),
+            events),
+        "fused_event_lif_decode": (
+            lambda *a: fk.fused_event_lif_decode_kernel(
+                *a, LEAK, n_out=G * P, n_groups=G, per_group=P,
+                interpret=False),
+            events),
+        "fused_event_lif_early_exit": (
+            lambda *a: fk.fused_event_lif_early_exit_kernel(
+                *a, LEAK, interpret=False),
+            events),
+        "lif": (
+            lambda c, t: lif_fused_kernel(c, t, LEAK, interpret=False),
+            [((T, B, N_PAD), I32), ((N_PAD,), I32)]),
+        "event_accum": (
+            lambda i, w: event_accum_kernel(i, w, interpret=False),
+            [((B, T, E_MAX), I32), ((N_IN, N_PAD), I8)]),
+        "ttfs_decode": (
+            lambda f, v: ttfs_decode_kernel(f, v, n_groups=G, per_group=P,
+                                            sentinel=T, interpret=False),
+            [((B, G * P), I32), ((B, G * P), I32)]),
+        "spike_matmul": (
+            lambda x, w: spike_matmul_kernel(x, w, interpret=False),
+            [((B * T, k_pad), I8), ((k_pad, N_PAD), I8)]),
+    }[name]
+
+
+@pytest.mark.parametrize("name", [
+    "fused_event_lif", "fused_event_lif_decode", "fused_event_lif_early_exit",
+    "lif", "event_accum", "ttfs_decode", "spike_matmul"])
+def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
+    fn, shapes = _case(name)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
