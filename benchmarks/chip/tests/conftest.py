@@ -1,0 +1,9 @@
+"""Make the benchmark and the program importable from the repo root."""
+
+import sys
+from pathlib import Path
+
+_ROOT = Path(__file__).resolve().parents[3]
+for p in (str(_ROOT / "src"), str(_ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
