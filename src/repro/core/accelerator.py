@@ -186,48 +186,37 @@ class SNNAccelerator:
         callers (the serving engine) that already validated the frames at pack
         time — the ``np.asarray(frames.overflow)`` read forces a device
         round-trip per call on pre-packed device-resident frames."""
-        # telemetry spans (accel.forward -> [pack] / kernel) are no-ops on
+        # telemetry spans (accel.forward -> [pack] / dispatch) are no-ops on
         # the shared NullRecorder — nothing below allocates when disabled
         rec = ttrace.get()
-        fwd = None
+        attrs = meta = None
         if rec.enabled:
             B = (int(frames.ids.shape[0]) if frames is not None
                  else int(np.atleast_2d(np.asarray(images)).shape[0]))
-            fwd = rec.begin("accel.forward", "system",
-                            attrs={"mode": self.mode, "batch": B,
-                                   "T": self.T,
-                                   "latency": bool(latency_mode)},
-                            meta={"kernel": self.kernel})
-        try:
+            attrs = {"mode": self.mode, "batch": B, "T": self.T,
+                     "latency": bool(latency_mode)}
+            meta = {"kernel": self.kernel}
+        with rec.span("accel.forward", "system", attrs=attrs, meta=meta):
             if self.mode == "batch":
                 assert images is not None, "batch mode consumes dense images"
-                kr = rec.begin("accel.kernel", "accel", trace=fwd.trace,
-                               parent=fwd.sid) if fwd is not None else None
-                out = self._fwd_batch(jnp.asarray(images, jnp.float32))
-                rec.end(kr)
-                return out
+                # the jitted call: transfer and enqueue, not the device work
+                with rec.span("accel.dispatch", "accel"):
+                    return self._fwd_batch(jnp.asarray(images, jnp.float32))
             if frames is None:
-                pk = rec.begin("accel.pack", "system", trace=fwd.trace,
-                               parent=fwd.sid,
-                               attrs={"e_max": self.e_max}) \
-                    if fwd is not None else None
-                times = np.asarray(ttfs.encode_ttfs(
-                    jnp.asarray(images, jnp.float32), self.T, self.x_min))
-                frames = pack_events_batched(times, self.T, self.e_max)
-                rec.end(pk)
+                with rec.span("accel.pack", "system",
+                              attrs={"e_max": self.e_max}
+                              if rec.enabled else None):
+                    times = np.asarray(ttfs.encode_ttfs(
+                        jnp.asarray(images, jnp.float32), self.T,
+                        self.x_min))
+                    frames = pack_events_batched(times, self.T, self.e_max)
             if check_overflow and bool(np.any(np.asarray(frames.overflow))):
                 raise OverflowError(
                     "event frames exceed artifact E_max; re-export with "
                     "larger headroom or use the dense batch path")
-            kr = rec.begin("accel.kernel", "accel", trace=fwd.trace,
-                           parent=fwd.sid) if fwd is not None else None
-            if latency_mode:
-                out = self._fwd_event_latency(frames.ids, frames.count)
-            else:
-                out = self._fwd_event(frames.ids, frames.count)
-            rec.end(kr)
-            return out
-        finally:
-            rec.end(fwd)
+            with rec.span("accel.dispatch", "accel"):
+                if latency_mode:
+                    return self._fwd_event_latency(frames.ids, frames.count)
+                return self._fwd_event(frames.ids, frames.count)
 
     __call__ = forward

@@ -151,10 +151,13 @@ def _fused_early_exit_kernel(ids_ref, count_ref, w_ref, thr_ref,
     steps_ref[0] = jnp.full((1, 1), t, jnp.int32)
 
 
-def _call(kernel, ids, count, w, thresholds, n_scalar_outs: int,
+def _call(kernel, name: str, ids, count, w, thresholds, n_scalar_outs: int,
           interpret: bool):
     """Shared pallas_call over grid (B,): (first (B, N), v (B, N)) plus
-    ``n_scalar_outs`` per-row int32 scalars (B,)."""
+    ``n_scalar_outs`` per-row int32 scalars (B,). ``name`` names the
+    kernel's custom call in the compiled program, whatever jitted function
+    calls it; profiler traces find the kernel by it (``fused_event_lif``
+    prefix)."""
     B, T, E = ids.shape
     N_in, N = w.shape
     row = pl.BlockSpec((1, 1, N), lambda b: (b, 0, 0))
@@ -178,6 +181,7 @@ def _call(kernel, ids, count, w, thresholds, n_scalar_outs: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name=name,
     )(ids, count.reshape(B, 1, T), w, thresholds.reshape(1, N))
     return ([outs[0][:, 0], outs[1][:, 0]]
             + [o[:, 0, 0] for o in outs[2:]])
@@ -192,7 +196,8 @@ def fused_event_lif_kernel(ids: jnp.ndarray, count: jnp.ndarray,
     -> (first_spike (B, N_pad), v_final (B, N_pad)) int32."""
     kernel = functools.partial(_fused_kernel, T=ids.shape[1],
                                leak_shift=leak_shift)
-    first, v = _call(kernel, ids, count, w, thresholds, 0, interpret)
+    first, v = _call(kernel, "fused_event_lif", ids, count, w, thresholds,
+                     0, interpret)
     return first, v
 
 
@@ -210,7 +215,8 @@ def fused_event_lif_decode_kernel(ids: jnp.ndarray, count: jnp.ndarray,
     kernel = functools.partial(
         _fused_decode_kernel, T=ids.shape[1], leak_shift=leak_shift,
         n_groups=n_groups, per_group=per_group, fallback=fallback)
-    first, v, labels = _call(kernel, ids, count, w, thresholds, 1, interpret)
+    first, v, labels = _call(kernel, "fused_event_lif_decode", ids, count, w,
+                             thresholds, 1, interpret)
     return first, v, labels
 
 
@@ -225,5 +231,6 @@ def fused_event_lif_early_exit_kernel(ids: jnp.ndarray, count: jnp.ndarray,
     contract as ``lif_scan_early_exit``."""
     kernel = functools.partial(_fused_early_exit_kernel, T=ids.shape[1],
                                leak_shift=leak_shift)
-    first, v, steps = _call(kernel, ids, count, w, thresholds, 1, interpret)
+    first, v, steps = _call(kernel, "fused_event_lif_early_exit", ids, count,
+                            w, thresholds, 1, interpret)
     return first, v, steps

@@ -223,19 +223,28 @@ class _Lane:
         from repro.core.events import pack_events_batched
         import jax.numpy as jnp
 
-        times = np.asarray(ttfs.encode_ttfs(
-            jnp.asarray(images, jnp.float32), self.T, self.x_min))
-        frames = pack_events_batched(times, self.T, self.e_max)
-        overflow = np.asarray(frames.overflow)  # checked ONCE, on host arrays
+        # phase spans (children of the batch's ``runtime`` span); no-ops on
+        # the shared NullRecorder
+        rec = ttrace.get()
+        with rec.span("lane.encode", "system"):
+            times = np.asarray(ttfs.encode_ttfs(
+                jnp.asarray(images, jnp.float32), self.T, self.x_min))
+        with rec.span("lane.pack", "system") as pack:
+            frames = pack_events_batched(times, self.T, self.e_max)
+            overflow = np.asarray(frames.overflow)  # checked ONCE, on host
+        if pack is not None:                 # real rows' events, host-side
+            pack.attrs["events"] = int(np.count_nonzero(times[:k] < self.T))
 
         t0 = time.perf_counter()
         out = self.runtime.forward(frames=frames,
                                    latency_mode=self.latency_mode,
                                    check_overflow=False)
-        jax.block_until_ready(out.labels)
+        with rec.span("lane.device_wait", "system"):
+            jax.block_until_ready(out.labels)
         accel_s = time.perf_counter() - t0
-        labels = np.array(out.labels)           # writable copies (reroute
-        steps = np.array(out.steps)             # rows are patched below)
+        with rec.span("lane.readback", "system"):
+            labels = np.array(out.labels)       # writable copies (reroute
+            steps = np.array(out.steps)         # rows are patched below)
 
         bad = np.nonzero(overflow[:k])[0]
         if bad.size:
@@ -243,13 +252,16 @@ class _Lane:
             # time-batched path (same artifact, same semantics, no E_max
             # cap). Runs on the full fixed-shape padded buffer so the dense
             # program compiles once, not per distinct overflow-row count.
-            self._ensure_dense()
-            t0 = time.perf_counter()
-            dense_out = self._dense.forward(images=images)
-            jax.block_until_ready(dense_out.labels)
-            accel_s += time.perf_counter() - t0
-            labels[bad] = np.asarray(dense_out.labels)[bad]
-            steps[bad] = np.asarray(dense_out.steps)[bad]
+            with rec.span("lane.reroute", "system",
+                          attrs={"rows": int(bad.size)}
+                          if rec.enabled else None):
+                self._ensure_dense()
+                t0 = time.perf_counter()
+                dense_out = self._dense.forward(images=images)
+                jax.block_until_ready(dense_out.labels)
+                accel_s += time.perf_counter() - t0
+                labels[bad] = np.asarray(dense_out.labels)[bad]
+                steps[bad] = np.asarray(dense_out.steps)[bad]
         return {"accel_s": accel_s, "labels": labels, "steps": steps,
                 "fallback": overflow, "overflow_fallbacks": int(bad.size)}
 
@@ -537,33 +549,47 @@ class ServingScheduler:
         return False
 
     # ------------------------------------------------------- batch formation
-    def _form_batch(self) -> list[ServeRequest] | None:
+    def _form_batch(self) -> tuple[list[ServeRequest], str | None] | None:
         """Blocking formation for worker lanes: open on the oldest queued
         request, close at max_batch OR max_wait_us — whichever first.
         ``solo`` requests (poison isolation after a batch failure) always
-        form a batch of one."""
+        form a batch of one. Returns (batch, trace id of the batch when a
+        Tracer is installed): the lane's wait for a first request
+        (``lane.idle``) and the formation (``batch.form``) are recorded in
+        the trace of the batch they lead to."""
+        rec = ttrace.get()
         with self._cv:
-            while not self._admission and not self._stop:
-                self._cv.wait()
+            trace = self._next_batch_trace() if rec.enabled else None
+            if not self._admission and not self._stop:
+                with rec.span("lane.idle", "system", trace=trace):
+                    while not self._admission and not self._stop:
+                        self._cv.wait()
             if self._stop:                   # no NEW batches after close():
                 return None                  # the backlog is failed, not served
-            batch = [self._admission.popleft()]
-            if batch[0].solo:
+            with rec.span("batch.form", "system", trace=trace):
+                batch = [self._admission.popleft()]
+                if batch[0].solo:
+                    self._sample_depth()
+                    return batch, trace
+                deadline = time.perf_counter() + self.max_wait_us * 1e-6
+                while len(batch) < self.max_batch:
+                    if self._admission:
+                        if self._admission[0].solo:
+                            break            # isolation batch forms alone
+                        batch.append(self._admission.popleft())
+                        continue
+                    remaining = deadline - time.perf_counter()
+                    if self._stop or remaining <= 0:
+                        break
+                    self._cv.wait(timeout=remaining)
                 self._sample_depth()
-                return batch
-            deadline = time.perf_counter() + self.max_wait_us * 1e-6
-            while len(batch) < self.max_batch:
-                if self._admission:
-                    if self._admission[0].solo:
-                        break                # isolation batch forms alone
-                    batch.append(self._admission.popleft())
-                    continue
-                remaining = deadline - time.perf_counter()
-                if self._stop or remaining <= 0:
-                    break
-                self._cv.wait(timeout=remaining)
-            self._sample_depth()
-            return batch
+            return batch, trace
+
+    def _next_batch_trace(self) -> str:
+        """Caller holds the lock: the trace id of the next batch."""
+        seq = self._batch_seq
+        self._batch_seq += 1
+        return f"batch-{seq:06d}"
 
     def _worker(self, lane_id: int, gen: int) -> None:
         while True:
@@ -573,10 +599,10 @@ class ServingScheduler:
                 lane = self.lanes[lane_id]
                 if lane.retired:
                     return
-            batch = self._form_batch()
-            if batch is None:
+            formed = self._form_batch()
+            if formed is None:
                 return
-            self._serve_batch(lane, batch)
+            self._serve_batch(lane, *formed)
 
     def _drain_inline(self) -> None:
         """Inline mode: greedy max_batch-sized batches on the caller thread
@@ -591,7 +617,8 @@ class ServingScheduler:
             self._serve_batch(self.lanes[0], batch)
 
     # -------------------------------------------------------------- serving
-    def _serve_batch(self, lane: _Lane, batch: list[ServeRequest]) -> None:
+    def _serve_batch(self, lane: _Lane, batch: list[ServeRequest],
+                     trace: str | None = None) -> None:
         t0 = time.perf_counter()
         k = len(batch)
         pairs = [(r, r.attempts) for r in batch]   # completion tokens
@@ -599,39 +626,31 @@ class ServingScheduler:
         lane.busy_since = t0
         lane.batches_served += 1
         rec = ttrace.get()
-        bspan = lspan = None
+        bspan = None
         if rec.enabled:
-            with self._lock:
-                seq = self._batch_seq
-                self._batch_seq += 1
-            bspan = rec.begin("batch", "system", trace=f"batch-{seq:06d}",
+            if trace is None:                # inline mode forms no batch
+                with self._lock:
+                    trace = self._next_batch_trace()
+            bspan = rec.begin("batch", "system", trace=trace,
                               attrs={"k": k, "max_batch": self.max_batch},
                               meta={"lane": lane.lane_id,
+                                    "health": lane.health,
                                     "rids": [r.rid for r in batch]})
             for r, _ in pairs:
                 rec.end(r._adm)     # admission ends where the batch forms
-                if r._span is not None:
-                    rec.emit("batch-form", "system", trace=r._span.trace,
-                             parent=r._span.sid, meta={"batch": seq})
-            if bspan is not None:
-                lspan = rec.begin("lane", "system", trace=bspan.trace,
-                                  parent=bspan.sid,
-                                  meta={"lane": lane.lane_id,
-                                        "health": lane.health})
+        bsid = bspan.sid if bspan is not None else None
         failure: str | None = None
         exc: BaseException | None = None
         delta = None
         try:
-            images = np.zeros((self.max_batch, self.n_in), np.float32)
-            for j, r in enumerate(batch):
-                images[j] = r.image          # zero-pad to the fixed shape
-            if lspan is not None:
-                # context-managed so the runtime's own spans (board.forward,
-                # accel.kernel, …) nest under this batch's tree
-                with rec.span("runtime", "system", trace=bspan.trace,
-                              parent=lspan.sid, meta={"spec": lane.spec}):
-                    delta = lane.serve(images, k)
-            else:
+            with rec.span("lane.pad", "system", trace=trace, parent=bsid):
+                images = np.zeros((self.max_batch, self.n_in), np.float32)
+                for j, r in enumerate(batch):
+                    images[j] = r.image      # zero-pad to the fixed shape
+            # context-managed so the runtime's own spans (board.forward,
+            # accel.forward, lane.encode, …) nest under this batch's tree
+            with rec.span("runtime", "system", trace=trace, parent=bsid,
+                          meta={"spec": lane.spec} if bspan else None):
                 delta = lane.serve(images, k)
             if self.resilience.verify:
                 errs = self._verify_errors(lane, images)
@@ -644,7 +663,6 @@ class ServingScheduler:
             lane.busy_since = None
             lane.current = None
         now = time.perf_counter()
-        rec.end(lspan)
         if bspan is not None:
             rec.end(bspan, attrs={"failed": failure is not None})
 
@@ -663,10 +681,10 @@ class ServingScheduler:
             self._handle_lane_fault(lane, pairs, failure)
             return
 
-        with self._cv:
+        with rec.span("batch.complete", "system", trace=trace), self._cv:
             if self.lanes[lane.lane_id] is not lane or lane.hung:
                 return  # superseded mid-serve; the watchdog requeued these
-            completed = 0
+            completed = steps = 0
             m = self.metrics
             for j, (r, tok) in enumerate(pairs):
                 if r.rid not in self._outstanding or r.attempts != tok:
@@ -680,8 +698,10 @@ class ServingScheduler:
                 m.observe("request_latency_us", r.latency_us,
                           LATENCY_BUCKETS_US)
                 completed += 1
+                steps += r.steps
             self._pending -= completed
             m.inc("images_out", completed)
+            m.inc("steps_served", steps)
             m.inc("batches")
             m.observe("batch_fill", k, DEPTH_BUCKETS)
             m.inc("accel_s", delta["accel_s"])
@@ -1085,6 +1105,8 @@ class ServingScheduler:
             "queue_depth_mean": snap.get("queue_depth_mean", 0.0),
             "queue_depth_peak": int(snap.get("queue_depth_peak", 0)),
             "batch_fill_mean": snap.get("batch_fill_mean", 0.0),
+            # timesteps the served rows ran, of T (early exit saves the rest)
+            "mean_steps": per_image(float(snap.get("steps_served", 0))),
             # ---- resilience ledger (counters from the same snapshot) ----
             "lane_faults": int(snap.get("lane_faults", 0)),
             "requeued": int(snap.get("requeued", 0)),

@@ -1,7 +1,9 @@
 """Deterministic structured tracing — the span side of the telemetry tier.
 
-A span records one step of a request's life (``submit -> admission ->
-batch-form -> lane -> runtime -> kernel -> decode -> complete``) with
+A span records one step of a request's or a batch's life (``request ->
+admission -> complete``; ``batch.form -> batch -> lane.pad / runtime ->
+lane.encode / lane.pack / accel.forward / lane.device_wait / lane.readback
+-> batch.complete``) with
 
   * an explicit **scope tag** on every span — ``"accel"`` (device/datapath
     work only: the paper's accelerator-scope) or ``"system"`` (everything a
@@ -11,11 +13,16 @@ batch-form -> lane -> runtime -> kernel -> decode -> complete``) with
   * **logical clocks** in ``attrs`` — tick / event / cycle counts taken from
     the board cost model (deterministic, seed-reproducible integers), the
     currency every cross-run comparison uses;
-  * **wall clocks** in dedicated fields (``wall_ns_start`` / ``wall_ns_end``)
-    and host-only context in ``meta`` (lane id, thread, runtime impl) —
-    excluded from the canonical form, so two runs of the same seed produce
-    **bit-identical canonical span trees** even though wall time and thread
-    placement differ.
+  * **wall clocks** in dedicated fields (``wall_ns_start`` / ``wall_ns_end``),
+    the recording thread's **CPU time** inside a context-managed span
+    (``cpu_ns``, from ``time.thread_time_ns``) and host-only context in
+    ``meta`` (lane id, thread, runtime impl) — excluded from the canonical
+    form, so two runs of the same seed produce **bit-identical canonical
+    span trees** even though wall time and thread placement differ.
+
+While a ``Tracer`` is installed, every context-managed span is mirrored as a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace shows
+the spans on its own host clock next to the device's operations.
 
 Span ids are sequential *per trace* (a trace is one request, one batch, or
 one standalone forward), and parent/child causality is explicit — the tree
@@ -58,7 +65,7 @@ class Span:
     hold host-nondeterministic context and are excluded from ``canonical``."""
 
     __slots__ = ("trace", "sid", "parent", "name", "scope", "attrs", "meta",
-                 "wall_ns_start", "wall_ns_end")
+                 "wall_ns_start", "wall_ns_end", "cpu_ns")
 
     def __init__(self, trace: str, sid: int, parent: int | None, name: str,
                  scope: str, attrs: dict | None, meta: dict | None,
@@ -72,6 +79,9 @@ class Span:
         self.meta = meta if meta is not None else {}
         self.wall_ns_start = wall_ns_start
         self.wall_ns_end = wall_ns_start
+        # thread CPU time between entry and exit of a context-managed span
+        # (None for begin/end and emitted spans, which may cross threads)
+        self.cpu_ns: int | None = None
 
     @property
     def wall_us(self) -> float:
@@ -84,32 +94,47 @@ class Span:
                 "name": self.name, "scope": self.scope, "attrs": self.attrs}
 
     def full(self) -> dict:
-        """The export form: canonical + wall clocks + host meta."""
+        """The export form: canonical + wall clocks + CPU time + host meta."""
         d = self.canonical()
         d["wall_ns_start"] = self.wall_ns_start
         d["wall_ns_end"] = self.wall_ns_end
+        d["cpu_ns"] = self.cpu_ns
         d["meta"] = self.meta
         return d
 
 
 class _SpanCtx:
-    """Context manager wrapping begin/end with thread-local nesting."""
+    """Context manager wrapping begin/end with thread-local nesting, the
+    thread's CPU time and a profiler annotation of the span's name. The CPU
+    clock and the annotation are read inside the wall-clock interval, so
+    with a fine-grained CPU clock ``cpu_ns`` never exceeds the span's wall
+    time. Where the kernel counts thread CPU time in scheduler ticks (10 ms
+    steps on a TPU v5e host), a span reads whole ticks: only sums over many
+    spans are meaningful there."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_annotation", "_cpu_ns0")
 
     def __init__(self, tracer: "Tracer", span: Span | None):
         self._tracer = tracer
         self._span = span
 
     def __enter__(self) -> Span | None:
-        if self._span is not None:
-            self._tracer._push(self._span)
-        return self._span
+        span = self._span
+        if span is not None:
+            self._tracer._push(span)
+            self._annotation = self._tracer._annotate(span.name)
+            self._annotation.__enter__()
+            self._cpu_ns0 = time.thread_time_ns()
+        return span
 
     def __exit__(self, *exc) -> bool:
-        if self._span is not None:
-            self._tracer._pop(self._span)
-            self._span.wall_ns_end = time.perf_counter_ns()
+        span = self._span
+        if span is not None:
+            cpu_ns = time.thread_time_ns() - self._cpu_ns0
+            self._annotation.__exit__(*exc)
+            self._tracer._pop(span)
+            span.wall_ns_end = time.perf_counter_ns()
+            span.cpu_ns = cpu_ns
         return False
 
 
@@ -163,6 +188,8 @@ class Tracer:
         self._auto = itertools.count()        # standalone-trace id counter
         self._sids: dict[str, itertools.count] = {}
         self._tls = threading.local()
+        from jax.profiler import TraceAnnotation
+        self._annotate = TraceAnnotation
 
     # ------------------------------------------------------------ internals
     def _stack(self) -> list[Span]:
@@ -217,7 +244,9 @@ class Tracer:
              parent: int | None = None, attrs: dict | None = None,
              meta: dict | None = None) -> _SpanCtx:
         """Context-managed span: nests via a thread-local stack, so spans
-        opened inside it (same thread) become its children automatically."""
+        opened inside it (same thread) become its children automatically.
+        Records the thread's CPU time (``cpu_ns``) and mirrors the span as a
+        profiler annotation of the same name."""
         return _SpanCtx(self, self._record(name, scope, trace, parent,
                                            attrs, meta))
 

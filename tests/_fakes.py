@@ -6,16 +6,20 @@ the conformance oracles exist to catch. ``BrokenRuntime`` raises on every
 forward, as a program the device's compiler refuses would. ``registered_family`` temporarily
 installs a factory in ``runtimes._REGISTRY`` and guarantees cleanup, so a
 test cannot leak a fake family into the rest of the suite (which would fail
-the registry-consistency oracle everywhere else).
+the registry-consistency oracle everywhere else). ``tiny_emax_artifact``
+clones an artifact with an event-buffer depth far too small, which forces
+the overflow → dense-reroute path.
 """
 
 from __future__ import annotations
 
 import contextlib
+import copy
 
 import numpy as np
 
 from repro.core import runtimes
+from repro.core.artifact import Artifact
 from repro.core.reference import SNNOutput, SNNReference
 
 
@@ -60,3 +64,11 @@ class BrokenRuntime:
 def broken_family(name: str = "broken"):
     with registered_family(name, lambda art, opts, **kw: BrokenRuntime(art)):
         yield
+
+
+def tiny_emax_artifact(art: Artifact, e_max: int = 8) -> Artifact:
+    """In-memory clone whose calibrated event-buffer depth is far too small —
+    forces the overflow → dense-fallback path."""
+    clone = Artifact(copy.deepcopy(art.meta), dict(art.arrays))
+    clone.meta["events"]["e_max"] = e_max
+    return clone

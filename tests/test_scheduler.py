@@ -2,24 +2,16 @@
 worker lanes, latency percentiles, result()/drain() APIs, and the
 single-code-path overflow reroute / board accounting."""
 
-import copy
 import threading
 import time
 
 import numpy as np
 import pytest
 
-from repro.core.artifact import Artifact
 from repro.core.reference import SNNReference
 from repro.serving.scheduler import ServingError, ServingScheduler
 
-from _fakes import broken_family
-
-
-def _tiny_emax_artifact(art: Artifact, e_max: int = 8) -> Artifact:
-    clone = Artifact(copy.deepcopy(art.meta), dict(art.arrays))
-    clone.meta["events"]["e_max"] = e_max
-    return clone
+from _fakes import broken_family, tiny_emax_artifact
 
 
 def test_inline_mode_greedy_deterministic_batches(trained_artifact):
@@ -97,7 +89,7 @@ def test_overflow_reroute_lives_in_scheduler(trained_artifact):
     """The overflow→dense reroute is scheduler-side: rows beyond E_max are
     served through the dense path in ANY mode, labels still exact."""
     art, _, (xte, _) = trained_artifact
-    tiny = _tiny_emax_artifact(art, e_max=8)
+    tiny = tiny_emax_artifact(art, e_max=8)
     want = np.asarray(SNNReference(art).forward(xte[:24]).labels)
     with ServingScheduler(tiny, spec="accelerator-event", kernel="fused",
                           workers=1, max_batch=8, max_wait_us=500.0) as s:

@@ -1,24 +1,15 @@
 """Batched SNN serving engine: queueing, micro-batching, overflow fallback,
 scope-aware stats — plus the event-path edge cases the engine relies on."""
 
-import copy
-
 import numpy as np
 import pytest
 
 from repro.core import events
 from repro.core.accelerator import SNNAccelerator
-from repro.core.artifact import Artifact
 from repro.core.reference import SNNReference
 from repro.serving.snn_engine import SNNServeEngine
 
-
-def _tiny_emax_artifact(art: Artifact, e_max: int = 8) -> Artifact:
-    """In-memory clone whose calibrated event-buffer depth is far too small —
-    forces the overflow → dense-fallback path."""
-    clone = Artifact(copy.deepcopy(art.meta), dict(art.arrays))
-    clone.meta["events"]["e_max"] = e_max
-    return clone
+from _fakes import tiny_emax_artifact
 
 
 # ----------------------------------------------------------------- serving
@@ -66,7 +57,7 @@ def test_engine_overflow_falls_back_to_dense(trained_artifact):
     """Rows whose frames exceed E_max must be served via the dense batch
     path, not dropped — labels still match the reference exactly."""
     art, _, (xte, _) = trained_artifact
-    tiny = _tiny_emax_artifact(art, e_max=8)
+    tiny = tiny_emax_artifact(art, e_max=8)
     eng = SNNServeEngine(tiny, max_batch=16, kernel="fused")
     got = eng.classify(xte[:32])
     want = np.asarray(SNNReference(art).forward(xte[:32]).labels)
@@ -133,7 +124,7 @@ def test_engine_stats_percentiles_and_workers(trained_artifact):
 # ------------------------------------------------------- event path edges
 def test_accelerator_overflow_raises_and_opt_out(trained_artifact):
     art, _, (xte, _) = trained_artifact
-    tiny = _tiny_emax_artifact(art, e_max=8)
+    tiny = tiny_emax_artifact(art, e_max=8)
     acc = SNNAccelerator(tiny, mode="event", kernel="fused")
     with pytest.raises(OverflowError):
         acc.forward(xte[:8])

@@ -101,3 +101,32 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", ["fused_event_lif_decode",
+                                  "fused_event_lif_early_exit"])
+def test_served_kernel_keeps_the_name_the_benchmark_reads(
+        name, one_chip, no_persistent_cache):
+    """The benchmark finds the fused kernel in a profiler trace by a rule on
+    the custom call's HLO instruction (``benchmarks/chip/kernels.json``).
+    The kernel's ``name`` fixes that instruction's name whatever jitted
+    function calls it, so a refactor cannot silently leave the roofline
+    with nothing to read."""
+    import json
+    import re
+    from pathlib import Path
+    rules = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                        / "chip" / "kernels.json").read_text())
+    rule = re.compile(rules["kernels"]["fused_event_lif"]["match"])
+    fn, shapes = _case(name)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    lines = [line.strip().removeprefix("ROOT ") for line in
+             jax.jit(fn).lower(*args).compile().as_text().splitlines()]
+    calls = [line for line in lines
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert calls[0].startswith(f"%{name}.") or calls[0].startswith(
+        f"%{name} ")
+    assert rule.search(calls[0])
+    assert not [line for line in lines if line not in calls
+                and rule.search(line)]
