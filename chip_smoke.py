@@ -10,11 +10,14 @@ One process, one chip. Steps:
      procedural MNIST (8,192 training images), a few epochs of the dense
      proxy trainer on the chip, then ``deploy.export`` into a fresh
      ``results/chip_smoke/``.
-  3. Serve 10,000 procedural test images through ``SNNServeEngine`` with
+  3. Pack the 10,000 test images' events twice, on the chip by the served
+     packer and on the host by ``pack_events_batched``, and compare the
+     frames element for element.
+  4. Serve 10,000 procedural test images through ``SNNServeEngine`` with
      its defaults (``accelerator-event-fused``, ``max_batch=64``), full-T
      and then in latency mode; run the dense ``accelerator-batch`` runtime
      on the same images.
-  4. Check every served label, and the first-spike times of the same specs
+  5. Check every served label, and the first-spike times of the same specs
      run through ``make_runtime``, elementwise against ``SNNReference`` on
      the chip and on the host CPU; check the fused Pallas kernel is in the
      served program (``tpu_custom_call``) and that serving saw no fault.
@@ -150,36 +153,69 @@ def served_path_outputs(art, images, max_batch, latency):
 
 
 def kernel_in_program(eng, latency) -> bool:
-    """Does the engine's compiled event program hold a Pallas TPU kernel?"""
+    """Does the engine's compiled event program (encode, device packing,
+    kernel over a ``max_batch`` image buffer) hold a Pallas TPU kernel?"""
     import jax
     import jax.numpy as jnp
     acc = eng.accel
-    fn = acc._fwd_event_latency if latency else acc._fwd_event
-    ids = jax.ShapeDtypeStruct((eng.max_batch, acc.T, acc.e_max), jnp.int32)
-    count = jax.ShapeDtypeStruct((eng.max_batch, acc.T), jnp.int32)
-    return "tpu_custom_call" in fn.lower(ids, count).compile().as_text()
+    fn = acc._fwd_images_latency if latency else acc._fwd_images
+    images = jax.ShapeDtypeStruct((eng.max_batch, acc.program.n_in),
+                                  jnp.float32)
+    return "tpu_custom_call" in fn.lower(images).compile().as_text()
 
 
 def time_batch_ms(eng, images, latency, iters=50):
-    """Median device time of one warm ``max_batch`` event call (frames
-    already on the device), ended by block_until_ready."""
+    """Median device time of one warm ``max_batch`` event call (the image
+    buffer already on the device; encode and packing included), ended by
+    block_until_ready."""
     import numpy as np
     import jax
-    import jax.numpy as jnp
-    from repro.core import ttfs
-    from repro.core.events import pack_events_batched
     acc = eng.accel
-    times = np.asarray(ttfs.encode_ttfs(jnp.asarray(images[:eng.max_batch]),
-                                        acc.T, acc.x_min))
-    frames = pack_events_batched(times, acc.T, acc.e_max)
-    run = acc._fwd_event_latency if latency else acc._fwd_event
-    jax.block_until_ready(run(frames.ids, frames.count))
+    buf = jax.device_put(np.asarray(images[:eng.max_batch]))
+    run = acc._fwd_images_latency if latency else acc._fwd_images
+    jax.block_until_ready(run(buf))
     ts = []
     for _ in range(iters):
         t0 = time.perf_counter()
-        jax.block_until_ready(run(frames.ids, frames.count))
+        jax.block_until_ready(run(buf))
         ts.append(time.perf_counter() - t0)
     return 1e3 * float(np.median(ts))
+
+
+def device_pack_mismatches(art, images, max_batch):
+    """Pack every ``max_batch`` chunk of ``images`` twice from the same
+    spike times, encoded on the chip: on the chip by the served packer
+    (``pack_events_device``) and on the host by ``pack_events_batched``.
+    Returns the rows whose frames differ in ids, count, overflow or event
+    total, and the overflowing rows."""
+    import numpy as np
+    import jax
+    from repro.core import ttfs
+    from repro.core.events import pack_events_batched, pack_events_device
+    from repro.core.lowering import lower
+    prog = lower(art)
+    encode = jax.jit(lambda x: ttfs.encode_ttfs(x, prog.T, prog.x_min))
+    pack = jax.jit(lambda t: pack_events_device(t, prog.T, prog.e_max))
+    bad = {"ids": 0, "count": 0, "overflow": 0, "events": 0}
+    overflow_rows = 0
+    for i in range(0, len(images), max_batch):
+        x = np.zeros((max_batch, images.shape[1]), np.float32)
+        k = len(images[i:i + max_batch])
+        x[:k] = images[i:i + max_batch]
+        times = encode(x)
+        ids, count, over, per_row = jax.device_get(pack(times))
+        times = np.asarray(times)
+        host = pack_events_batched(times, prog.T, prog.e_max)
+        want = {"ids": np.asarray(host.ids), "count": np.asarray(host.count),
+                "overflow": np.asarray(host.overflow),
+                "events": np.count_nonzero(times < prog.T, axis=1)}
+        got = {"ids": ids, "count": count, "overflow": over,
+               "events": per_row}
+        for key in bad:
+            diff = got[key][:k] != want[key][:k]
+            bad[key] += int(np.sum(diff.reshape(k, -1).any(axis=1)))
+        overflow_rows += int(np.sum(want["overflow"][:k]))
+    return bad, overflow_rows
 
 
 def mismatches(got, want):
@@ -216,6 +252,12 @@ def main() -> int:
     ref_cpu = reference(art, xte, device=jax.devices("cpu")[0])
     print(f"reference on host CPU: {time.perf_counter() - t0:.1f}s")
     bad = {"chip reference vs CPU reference": mismatches(ref_cpu, ref)}
+
+    t0 = time.perf_counter()
+    pack_bad, over_rows = device_pack_mismatches(art, xte, 64)
+    print(f"device-packed vs host-packed frames: {N_TEST} images, "
+          f"{over_rows} overflow rows, {time.perf_counter() - t0:.1f}s")
+    bad["device-packed vs host-packed frames"] = pack_bad
 
     print("timings below are a smoke reading, not a benchmark")
     for latency in (False, True):

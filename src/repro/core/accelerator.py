@@ -14,7 +14,9 @@ conversion stage) and executes the padded block layout the planner emitted:
     buffers drive per-step gathers of weight rows (HBM->VMEM in the kernel),
     accumulated into the membrane block. Work scales with ACTIVE events, the
     paper's event-driven property, and an early-exit loop stops at the first
-    output spike (the TTFS decision point) for latency mode.
+    output spike (the TTFS decision point) for latency mode. Given images,
+    the TTFS encode and the packing (``events.pack_events_device``) run in
+    the same jitted program as the kernel: one upload, one dispatch.
 
   * ``kernel="jnp" | "pallas" | "fused"`` — the jnp path mirrors the kernel's
     block structure op-for-op (and is fast on this CPU-only container); the
@@ -38,7 +40,7 @@ import numpy as np
 
 from repro.core import ttfs
 from repro.core.artifact import Artifact
-from repro.core.events import EventFrames, PAD, pack_events_batched
+from repro.core.events import EventFrames, PAD, pack_events_device
 from repro.core.lif_dynamics import lif_scan, lif_scan_early_exit
 from repro.core.lowering import (LoweredProgram, get_cache, lower,
                                  program_nbytes)
@@ -140,10 +142,24 @@ def _build_bundle(prog: LoweredProgram, mode: str, kernel: str) -> dict:
         labels, first_l, v_l = decode_padded(res.first_spike, res.v_final)
         return SNNOutput(labels, first_l, v_l, steps)
 
+    def with_device_pack(forward):
+        """(B, N_in) float32 images -> (SNNOutput, overflow, events_per_row):
+        TTFS encode and event packing run in the same program as ``forward``,
+        so a batch crosses to the device once each way."""
+        def run(images: jnp.ndarray):
+            times = ttfs.encode_ttfs(images, T, x_min)
+            ids, count, overflow, events = pack_events_device(
+                times, T, prog.e_max)
+            return forward(ids, count), overflow, events
+        return run
+
     if mode == "batch":
         return {"batch": jax.jit(forward_batch)}
     return {"event": jax.jit(forward_event),
-            "event_latency": jax.jit(forward_event_latency)}
+            "event_latency": jax.jit(forward_event_latency),
+            "event_images": jax.jit(with_device_pack(forward_event)),
+            "event_images_latency": jax.jit(
+                with_device_pack(forward_event_latency))}
 
 
 class SNNAccelerator:
@@ -177,46 +193,65 @@ class SNNAccelerator:
         else:
             self._fwd_event = bundle["event"]
             self._fwd_event_latency = bundle["event_latency"]
+            self._fwd_images = bundle["event_images"]
+            self._fwd_images_latency = bundle["event_images_latency"]
 
     # -------------------------------------------------------------- frontend
+    def _span(self, rec, batch, latency_mode: bool):
+        """The ``accel.forward`` span of one call over the array ``batch``
+        (a no-op, reading nothing, when disabled)."""
+        attrs = meta = None
+        if rec.enabled:
+            shape = np.shape(batch)
+            attrs = {"mode": self.mode,
+                     "batch": int(shape[0]) if len(shape) > 1 else 1,
+                     "T": self.T, "latency": bool(latency_mode)}
+            meta = {"kernel": self.kernel}
+        return rec.span("accel.forward", "system", attrs=attrs, meta=meta)
+
+    def forward_images(self, images, latency_mode: bool = False):
+        """Event mode, one device call: TTFS encode, event packing and the
+        kernel on a (B, N_in) float32 batch. Returns (SNNOutput, overflow
+        (B,) bool, events_per_row (B,) int32), still on the device — the
+        caller decides what to read back. A row flagged in ``overflow`` ran
+        on frames truncated to E_max."""
+        rec = ttrace.get()
+        with self._span(rec, images, latency_mode):
+            # the jitted call: transfer and enqueue, not the device work
+            with rec.span("accel.dispatch", "accel"):
+                run = (self._fwd_images_latency if latency_mode
+                       else self._fwd_images)
+                return run(jnp.asarray(images, jnp.float32))
+
     def forward(self, images=None, frames: EventFrames | None = None,
                 latency_mode: bool = False,
                 check_overflow: bool = True) -> SNNOutput:
-        """``check_overflow=False`` skips the host-side overflow flag read for
-        callers (the serving engine) that already validated the frames at pack
-        time — the ``np.asarray(frames.overflow)`` read forces a device
-        round-trip per call on pre-packed device-resident frames."""
-        # telemetry spans (accel.forward -> [pack] / dispatch) are no-ops on
-        # the shared NullRecorder — nothing below allocates when disabled
+        """Images take the device-packed event program (event mode) or the
+        dense one (batch mode); pre-packed ``frames`` run the event kernel
+        alone. ``check_overflow`` reads the overflow flags back after the
+        call and raises if a row exceeded E_max; ``False`` skips that
+        device round trip for callers that read the flags themselves."""
+        # telemetry spans (accel.forward -> dispatch) are no-ops on the
+        # shared NullRecorder — nothing below allocates when disabled
         rec = ttrace.get()
-        attrs = meta = None
-        if rec.enabled:
-            B = (int(frames.ids.shape[0]) if frames is not None
-                 else int(np.atleast_2d(np.asarray(images)).shape[0]))
-            attrs = {"mode": self.mode, "batch": B, "T": self.T,
-                     "latency": bool(latency_mode)}
-            meta = {"kernel": self.kernel}
-        with rec.span("accel.forward", "system", attrs=attrs, meta=meta):
-            if self.mode == "batch":
-                assert images is not None, "batch mode consumes dense images"
-                # the jitted call: transfer and enqueue, not the device work
-                with rec.span("accel.dispatch", "accel"):
-                    return self._fwd_batch(jnp.asarray(images, jnp.float32))
-            if frames is None:
-                with rec.span("accel.pack", "system",
-                              attrs={"e_max": self.e_max}
-                              if rec.enabled else None):
-                    times = np.asarray(ttfs.encode_ttfs(
-                        jnp.asarray(images, jnp.float32), self.T,
-                        self.x_min))
-                    frames = pack_events_batched(times, self.T, self.e_max)
-            if check_overflow and bool(np.any(np.asarray(frames.overflow))):
-                raise OverflowError(
-                    "event frames exceed artifact E_max; re-export with "
-                    "larger headroom or use the dense batch path")
-            with rec.span("accel.dispatch", "accel"):
-                if latency_mode:
-                    return self._fwd_event_latency(frames.ids, frames.count)
-                return self._fwd_event(frames.ids, frames.count)
+        if self.mode == "batch":
+            assert images is not None, "batch mode consumes dense images"
+            with self._span(rec, images, latency_mode), \
+                    rec.span("accel.dispatch", "accel"):
+                return self._fwd_batch(jnp.asarray(images, jnp.float32))
+        if frames is None:
+            out, overflow, _ = self.forward_images(images, latency_mode)
+        else:
+            with self._span(rec, frames.ids, latency_mode), \
+                    rec.span("accel.dispatch", "accel"):
+                run = (self._fwd_event_latency if latency_mode
+                       else self._fwd_event)
+                out = run(frames.ids, frames.count)
+            overflow = frames.overflow
+        if check_overflow and bool(np.any(np.asarray(overflow))):
+            raise OverflowError(
+                "event frames exceed artifact E_max; re-export with "
+                "larger headroom or use the dense batch path")
+        return out
 
     __call__ = forward

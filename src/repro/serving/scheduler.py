@@ -140,9 +140,10 @@ class ServeRequest:
 
 class _Lane:
     """One worker lane: a runtime built from the spec, plus the lane-local
-    serve path (event packing, overflow reroute, board accounting) and the
-    lane's health record. Each lane's counters are merged into the scheduler
-    under its lock, so lanes themselves stay lock-free on the hot path."""
+    serve path (the device-packed event call, overflow reroute, board
+    accounting) and the lane's health record. Each lane's counters are
+    merged into the scheduler under its lock, so lanes themselves stay
+    lock-free on the hot path."""
 
     def __init__(self, lane_id: int, artifact: Artifact, spec: str,
                  kernel: str | None, latency_mode: bool,
@@ -165,9 +166,6 @@ class _Lane:
             kw["faults"] = plan          # static/dynamic injection sites
         self.runtime = make_runtime(self.program, spec, **kw)
         self._dense = None               # built lazily on first overflow
-        self.T = self.program.T
-        self.x_min = self.program.x_min
-        self.e_max = self.program.e_max
         self.injector = None             # host-side fault site (lane faults)
         if plan is not None and plan.has_lane_faults:
             from repro.faults.models import LaneFaultInjector
@@ -218,35 +216,32 @@ class _Lane:
         return delta
 
     def _serve_event(self, images: np.ndarray, k: int) -> dict:
-        """Packed-event accelerator path with the overflow→dense reroute."""
-        from repro.core import ttfs
-        from repro.core.events import pack_events_batched
-        import jax.numpy as jnp
-
+        """Event accelerator path: the padded buffer goes to the device,
+        which encodes, packs and runs it in one call; the overflow flags
+        come back with the labels and drive the dense reroute."""
         # phase spans (children of the batch's ``runtime`` span); no-ops on
         # the shared NullRecorder
         rec = ttrace.get()
         with rec.span("lane.encode", "system"):
-            times = np.asarray(ttfs.encode_ttfs(
-                jnp.asarray(images, jnp.float32), self.T, self.x_min))
-        with rec.span("lane.pack", "system") as pack:
-            frames = pack_events_batched(times, self.T, self.e_max)
-            overflow = np.asarray(frames.overflow)  # checked ONCE, on host
-        if pack is not None:                 # real rows' events, host-side
-            pack.attrs["events"] = int(np.count_nonzero(times[:k] < self.T))
-
+            buf = jax.device_put(images)
         t0 = time.perf_counter()
-        out = self.runtime.forward(frames=frames,
-                                   latency_mode=self.latency_mode,
-                                   check_overflow=False)
+        out, overflow, events = self.runtime.forward_images(
+            buf, latency_mode=self.latency_mode)
         with rec.span("lane.device_wait", "system"):
             jax.block_until_ready(out.labels)
         accel_s = time.perf_counter() - t0
-        with rec.span("lane.readback", "system"):
-            labels = np.array(out.labels)       # writable copies (reroute
-            steps = np.array(out.steps)         # rows are patched below)
-
-        bad = np.nonzero(overflow[:k])[0]
+        with rec.span("lane.readback", "system") as readback:
+            labels, steps, overflow, events = jax.device_get(
+                (out.labels, out.steps, overflow, events))
+            # writable copies: reroute rows are patched below
+            labels, steps = labels.copy(), steps.copy()
+            n_events = int(events[:k].sum())     # the real rows' events
+            if readback is not None:
+                readback.attrs["events"] = n_events
+            bad = np.flatnonzero(overflow[:k])
+            delta = {"accel_s": accel_s, "labels": labels, "steps": steps,
+                     "fallback": overflow, "overflow_fallbacks": int(bad.size),
+                     "events_packed": n_events}
         if bad.size:
             # overflow policy: reroute those rows through the dense
             # time-batched path (same artifact, same semantics, no E_max
@@ -259,11 +254,10 @@ class _Lane:
                 t0 = time.perf_counter()
                 dense_out = self._dense.forward(images=images)
                 jax.block_until_ready(dense_out.labels)
-                accel_s += time.perf_counter() - t0
+                delta["accel_s"] += time.perf_counter() - t0
                 labels[bad] = np.asarray(dense_out.labels)[bad]
                 steps[bad] = np.asarray(dense_out.steps)[bad]
-        return {"accel_s": accel_s, "labels": labels, "steps": steps,
-                "fallback": overflow, "overflow_fallbacks": int(bad.size)}
+        return delta
 
     # ----------------------------------------------------- degraded fallback
     def _ensure_dense(self) -> None:
@@ -707,6 +701,7 @@ class ServingScheduler:
             m.inc("accel_s", delta["accel_s"])
             m.inc("system_s", now - t0)
             m.inc("overflow_fallbacks", delta["overflow_fallbacks"])
+            m.inc("events_packed", delta.get("events_packed", 0))
             m.inc("board_cycles", delta.get("board_cycles", 0))
             m.inc("board_nj", delta.get("board_nj", 0.0))
             m.inc("board_stalls", delta.get("board_stalls", 0))
@@ -1107,6 +1102,8 @@ class ServingScheduler:
             "batch_fill_mean": snap.get("batch_fill_mean", 0.0),
             # timesteps the served rows ran, of T (early exit saves the rest)
             "mean_steps": per_image(float(snap.get("steps_served", 0))),
+            # input events the device packed per served image (event path)
+            "mean_events": per_image(float(snap.get("events_packed", 0))),
             # ---- resilience ledger (counters from the same snapshot) ----
             "lane_faults": int(snap.get("lane_faults", 0)),
             "requeued": int(snap.get("requeued", 0)),

@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import events, ttfs
@@ -153,3 +154,54 @@ def test_packers_identical_tie_heavy_property(seed):
     times = np.where(mask, rng.randint(0, T + 1, (B, N)), times)
     times = times.astype(np.int32)
     _assert_packers_identical(times, T, e_max)
+
+
+# ------------------------------------------ device packer against the host's
+def _device_pack_case(name: str):
+    """(times, T, e_max) of one equality case."""
+    rng = np.random.RandomState(11)
+    if name == "random_images":
+        images = rng.rand(8, 784).astype(np.float32)
+        return np.asarray(ttfs.encode_ttfs(jnp.asarray(images), 32)), 32, 128
+    if name == "same_tick_flood":        # 3x the depth in one step, per row
+        T = 6
+        times = np.full((3, 48), T, np.int32)
+        for row, t in enumerate((0, T // 2, T - 1)):
+            times[row] = t
+        return times, T, 16
+    if name == "exact_e_max":            # e_max - 1, e_max, e_max + 1 events
+        T, e_max = 4, 8
+        times = np.full((3, e_max + 4), T, np.int32)
+        for row, n_ev in enumerate((e_max - 1, e_max, e_max + 1)):
+            times[row, :n_ev] = 1
+        return times, T, e_max
+    if name == "never_spike_rows":
+        T = 5
+        times = np.full((4, 20), T, np.int32)
+        times[2, :5] = np.arange(5) % T
+        return times, T, 8
+    if name == "zero_pad_rows":          # a served batch: 2 real rows of 8
+        images = np.zeros((8, 784), np.float32)
+        images[:2] = rng.rand(2, 784)
+        return np.asarray(ttfs.encode_ttfs(jnp.asarray(images), 32)), 32, 128
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("name", ["random_images", "same_tick_flood",
+                                  "exact_e_max", "never_spike_rows",
+                                  "zero_pad_rows"])
+def test_device_packer_equals_host_packer(name):
+    """``pack_events_device`` under jit gives ``pack_events_batched``'s
+    frames element for element, and each row's unclipped event count."""
+    import jax
+    times, T, e_max = _device_pack_case(name)
+    want = events.pack_events_batched(times, T, e_max)
+    ids, count, overflow, per_row = jax.jit(
+        events.pack_events_device, static_argnums=(1, 2))(
+            jnp.asarray(times, jnp.int32), T, e_max)
+    assert np.array_equal(np.asarray(ids), np.asarray(want.ids))
+    assert np.array_equal(np.asarray(count), np.asarray(want.count))
+    assert np.array_equal(np.asarray(overflow), np.asarray(want.overflow))
+    assert np.array_equal(np.asarray(per_row),
+                          np.count_nonzero(times < T, axis=1))
+    assert ids.dtype == jnp.int32 and count.dtype == jnp.int32

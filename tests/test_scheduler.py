@@ -102,6 +102,44 @@ def test_overflow_reroute_lives_in_scheduler(trained_artifact):
         assert any(done[r].fallback_dense for r in rids)
 
 
+def test_device_flagged_rows_and_only_those_take_the_dense_reroute(
+        trained_artifact):
+    """The overflow flags the device packer returns route exactly the rows
+    whose events exceed E_max through the dense path, with the dense path's
+    labels and steps; every other row keeps the event path's early exit."""
+    import jax.numpy as jnp
+
+    from repro.core import events, ttfs
+    art, _, (xte, _) = trained_artifact
+    tiny = tiny_emax_artifact(art, e_max=8)
+    T = int(art.m("encode", "T"))
+    x_min = float(art.m("encode", "x_min"))
+    images = np.array(xte[:12])
+    for row in images[::2]:              # every other row: 6 events at most
+        keep = np.flatnonzero(row >= x_min)[:6]
+        sparse = np.zeros_like(row)
+        sparse[keep] = row[keep]
+        row[:] = sparse
+    times = np.asarray(ttfs.encode_ttfs(jnp.asarray(images), T, x_min))
+    over = np.asarray(events.pack_events_batched(times, T, 8).overflow)
+    assert 0 < over.sum() < len(images)
+    ref = SNNReference(art).forward(images)
+    first = np.asarray(ref.first_spike).min(axis=1)
+    want_steps = np.where(over, T, np.where(first < T, first + 1, T))
+    with ServingScheduler(tiny, spec="accelerator-event", kernel="fused",
+                          max_batch=8, latency_mode=True) as s:
+        rids = [s.submit(x) for x in images]
+        done = s.drain()
+        st = s.stats()
+    assert [done[r].fallback_dense for r in rids] == list(over)
+    assert np.array_equal([done[r].label for r in rids],
+                          np.asarray(ref.labels))
+    assert np.array_equal([done[r].steps for r in rids], want_steps)
+    assert st["overflow_fallbacks"] == int(over.sum())
+    assert st["mean_events"] == pytest.approx(
+        np.count_nonzero(times < T) / len(images))
+
+
 def test_board_accounting_and_denominators(trained_artifact):
     art, _, (xte, _) = trained_artifact
     s = ServingScheduler(art, spec="board-batched", max_batch=16)

@@ -402,19 +402,19 @@ def test_scheduler_inline_spans_deterministic_and_causal(trained_artifact):
 SERVED_BATCH_TREE = [
     ("lane.idle", None), ("batch.form", None), ("batch", None),
     ("lane.pad", "batch"), ("runtime", "batch"),
-    ("lane.encode", "runtime"), ("lane.pack", "runtime"),
+    ("lane.encode", "runtime"),
     ("accel.forward", "runtime"), ("accel.dispatch", "accel.forward"),
     ("lane.device_wait", "runtime"), ("lane.readback", "runtime"),
     ("batch.complete", None)]
 # the lane's phases inside its runtime call
-RUNTIME_PHASES = ("lane.encode", "lane.pack", "accel.dispatch",
-                  "lane.device_wait", "lane.readback", "lane.reroute")
+RUNTIME_PHASES = ("lane.encode", "accel.dispatch", "lane.device_wait",
+                  "lane.readback", "lane.reroute")
 
 
 def _serve_one_at_a_time(art, images, tracer):
     """Serve ``images`` through one worker lane, one request per batch, the
-    lane idle before each; returns {rid: trace of the batch that served
-    it}."""
+    lane idle before each; returns ({rid: trace of the batch that served
+    it}, the scheduler's stats)."""
     from repro.serving.scheduler import ServingScheduler
     prev = ttrace.install(tracer)
     try:
@@ -423,10 +423,11 @@ def _serve_one_at_a_time(art, images, tracer):
             for x in images:
                 time.sleep(0.05)            # the lane is back waiting
                 s.result(s.submit(x), timeout=60)
+            stats = s.stats()
     finally:
         ttrace.install(prev)
     return {rid: b.trace for b in tracer.roots("batch")
-            for rid in b.meta["rids"]}
+            for rid in b.meta["rids"]}, stats
 
 
 def _tree(tracer, trace):
@@ -442,7 +443,7 @@ def test_served_batch_records_every_phase_span(trained_artifact, overflow):
     if overflow:
         art = tiny_emax_artifact(art)
     t = Tracer()
-    batches = _serve_one_at_a_time(art, xte[:2], t)
+    batches, stats = _serve_one_at_a_time(art, xte[:2], t)
     assert len(batches) == 2
     want = list(SERVED_BATCH_TREE)
     if overflow:       # the row overflows E_max = 8: the dense forward
@@ -458,7 +459,8 @@ def test_served_batch_records_every_phase_span(trained_artifact, overflow):
     # accel.forward stays a direct child of runtime (benchmark readers key
     # it by (trace, parent))
     assert spans["accel.forward"].parent == spans["runtime"].sid
-    assert spans["lane.pack"].attrs["events"] > 0
+    assert spans["lane.readback"].attrs["events"] > 0
+    assert stats["mean_events"] > 0
     if overflow:
         assert spans["lane.reroute"].attrs == {"rows": 1}
     # phases follow one another: nothing of the batch overlaps its wait

@@ -4,13 +4,16 @@ widths, compiled for a described TPU v5e chip with no chip attached.
 Interpret mode (every other kernel test) cannot see the TPU's tiling rules;
 the TPU compiler can, and refuses what the chip would refuse. The deployed
 widths: B = 64 (``SNNServeEngine``'s default ``max_batch``), T = 32,
-N_in = 784, N_pad = 256, E_max = 128, 150 outputs in 10 groups of 15.
+N_in = 784, N_pad = 256, E_max = 128, 150 outputs in 10 groups of 15. The
+served event program (TTFS encode, device packing, fused kernel) is also
+compiled whole, at N_pad 256 and 1664.
 
 The topology is described inside a module fixture (never at import), so
 only the pytest worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,6 +106,15 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _kernel_rule() -> re.Pattern:
+    """The benchmark's rule for the fused kernel's custom call."""
+    import json
+    from pathlib import Path
+    rules = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                        / "chip" / "kernels.json").read_text())
+    return re.compile(rules["kernels"]["fused_event_lif"]["match"])
+
+
 @pytest.mark.parametrize("name", ["fused_event_lif_decode",
                                   "fused_event_lif_early_exit"])
 def test_served_kernel_keeps_the_name_the_benchmark_reads(
@@ -112,12 +124,7 @@ def test_served_kernel_keeps_the_name_the_benchmark_reads(
     The kernel's ``name`` fixes that instruction's name whatever jitted
     function calls it, so a refactor cannot silently leave the roofline
     with nothing to read."""
-    import json
-    import re
-    from pathlib import Path
-    rules = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
-                        / "chip" / "kernels.json").read_text())
-    rule = re.compile(rules["kernels"]["fused_event_lif"]["match"])
+    rule = _kernel_rule()
     fn, shapes = _case(name)
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     lines = [line.strip().removeprefix("ROOT ") for line in
@@ -130,3 +137,54 @@ def test_served_kernel_keeps_the_name_the_benchmark_reads(
     assert rule.search(calls[0])
     assert not [line for line in lines if line not in calls
                 and rule.search(line)]
+
+
+def _program(cell_config: str):
+    """The lowered program of a benchmark configuration, its int8 weights
+    and thresholds drawn at random."""
+    import json
+    from pathlib import Path
+
+    import numpy as np
+
+    from benchmarks.chip import model
+    from repro.core.lowering import lower
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                      / "chip" / "configs" / f"{cell_config}.json").read_text())
+    rng = np.random.RandomState(0)
+    dep = model.Deployment(
+        cfg, rng.randint(-127, 128, (cfg["n_in"], cfg["n_out"])).astype(
+            np.int8), rng.randint(1, 2000, cfg["n_out"]).astype(np.int32),
+        0.01, 0.0)
+    return lower(model.artifact(dep), cache=False)
+
+
+@pytest.mark.parametrize("cell_config,n_pad", [("ttfs-784x150", N_PAD),
+                                              ("ttfs-784x1600", 1664)],
+                         ids=["n_pad_256", "n_pad_1664"])
+@pytest.mark.parametrize("entry", ["event_images", "event_images_latency"])
+def test_device_packed_event_program_compiles_for_v5e(
+        entry, cell_config, n_pad, one_chip, no_persistent_cache,
+        monkeypatch):
+    """The served program: TTFS encode, the device packer's int8 matmuls
+    and the fused kernel, as one jitted entry of the accelerator's bundle
+    over a (64, 784) float32 image batch. The packer leaves no gather,
+    sort or scatter in it: on a v5e a gather of the frames' 262,144 ids
+    took 2.6 ms a batch, more than four times the kernel."""
+    from repro.core.accelerator import _build_bundle
+    prog = _program(cell_config)
+    assert prog.w_padded.shape == (N_IN, n_pad)
+    assert (prog.T, prog.e_max) == (T, E_MAX)
+    # the kernel wrappers pick the Pallas path by the default backend,
+    # which is the CPU here; the program is compiled for the v5e
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn = _build_bundle(prog, "event", "fused")[entry]
+    images = jax.ShapeDtypeStruct((B, N_IN), jnp.float32, sharding=one_chip)
+    text = fn.lower(images).compile().as_text()
+    assert not re.search(r"\s(gather|sort|scatter)\(", text)
+    # one kernel call, under the name the benchmark's roofline reads
+    rule = _kernel_rule()
+    lines = [line.strip().removeprefix("ROOT ") for line in text.splitlines()]
+    calls = [line for line in lines
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and rule.search(calls[0])
