@@ -1,7 +1,9 @@
 """The control of the ``correct`` decision: the plain reference put in the
-program's place at int4 weights, one step below the int8 the configurations
-state, read by the same checks at a cell's own size. Every reading has to
-fail a limit of 0, or the checks could not tell the program from it.
+program's place one precision step below the configuration's (for the
+``model`` module, int4 weights against the int8 stated), as the cell's
+deployment module gives it, read by the same checks at a cell's own size.
+Every reading has to fail a limit of 0, or the checks could not tell the
+program from it.
 
     python3 benchmarks/chip/control.py --workload <cell> --requests <n> \
         --seeds 1 2 3
@@ -27,16 +29,15 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     a = ap.parse_args(argv)
     import numpy as np
-    from benchmarks.chip import harness, reference
+    from benchmarks.chip import harness
     cell = harness.load_cell(a.workload, trace=False)
     harness.find_chips(cell.chips)
     latency = bool(cell.traffic["serve"]["latency_mode"])
     for seed in a.seeds:
         dep, pool, order, _ = harness.inputs(cell, seed)
         img = order[np.arange(a.requests) % len(order)]
-        want = reference.answers(dep, pool, latency)
-        got = reference.answers(dep, pool, latency,
-                                weights=reference.int4_weights(dep.w_int8))
+        want = cell.module.answers(dep, pool, latency)
+        got = cell.module.answers(dep, pool, latency, control=True)
         print(json.dumps({
             "workload": a.workload, "seed": seed, "requests": a.requests,
             "wrong_labels": int(np.sum(got[0][img] != want[0][img])),

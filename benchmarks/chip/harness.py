@@ -6,7 +6,10 @@ reference, and print the result line.
 Everything a cell needs is found by name: ``BENCHMARK.json`` names the
 cell's configuration (``configs/<file>``), traffic (``traffic/<name>.json``)
 and metrics; each metric's reader is ``metrics/<name>.py``, or, for a
-metric named ``<base>.<variant>``, ``metrics/<base>.py``.
+metric named ``<base>.<variant>``, ``metrics/<base>.py``. The configuration
+names its deployment module (``"module"``, default ``model``; its interface
+is in ``model.py``'s docstring), which builds, exports and checks the
+deployment.
 """
 
 from __future__ import annotations
@@ -19,11 +22,12 @@ import shutil
 import sys
 import tempfile
 import time
+import types
 from pathlib import Path
 
 import numpy as np
 
-from benchmarks.chip import data, devtrace, load, model, reference, work
+from benchmarks.chip import data, devtrace, load, work
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -39,6 +43,12 @@ class Cell:
     cfg: dict
     traffic: dict
     metrics: list[dict]
+    module: types.ModuleType | None = None   # None: the one cfg names
+
+    def __post_init__(self):
+        if self.module is None:
+            self.module = importlib.import_module(
+                f"benchmarks.chip.{self.cfg.get('module', 'model')}")
 
 
 def load_cell(workload: str, trace: bool, root: Path = ROOT) -> Cell:
@@ -100,7 +110,8 @@ class Run:
     setup_s: float
     records: load.Records
     correct_rows: np.ndarray  # (len(records),) answer matches the reference
-    events: np.ndarray        # (pool,) events each pool image carries
+    events: np.ndarray        # (pool, L) events each pool image feeds a layer
+    widths: list              # [(n_in, n_out)] of each layer, real neurons
     stats: dict               # ServingScheduler.stats() after the window
     peak: dict                # the chip's peaks (work.load_peaks)
     spans: list | None = None         # program spans, traced run only
@@ -126,11 +137,11 @@ class Run:
                 self.t1 * 1e9 + self.offset_ns)
 
 
-def _warm(sched, pool: np.ndarray, n_in: int) -> None:
+def _warm(sched, pool: np.ndarray) -> None:
     """Serve every shape and path the window can take: full batches of pool
     images, and one image whose events overflow E_max in a step, which
     takes the dense reroute."""
-    overflow = np.ones(n_in, np.float32)
+    overflow = np.ones(pool.shape[1], np.float32)
     for _ in range(WARM_ROUNDS):
         rids = [sched.submit(img) for img in pool[:sched.max_batch]]
         rids.append(sched.submit(overflow))
@@ -180,7 +191,7 @@ def _compile_counter():
 def inputs(cell: Cell, seed: int):
     """(deployment, pool images, pool order, arrival seed) from the seed."""
     seed_model, seed_pool, seed_order, seed_arrivals = data.sub_seeds(seed, 4)
-    dep = model.build(cell.cfg, seed_model)
+    dep = cell.module.build(cell.cfg, seed_model)
     pool, _ = data.generate(cell.traffic["pool_images"], seed_pool)
     order = np.random.RandomState(seed_order).permutation(len(pool))
     return dep, pool, order, seed_arrivals
@@ -195,15 +206,15 @@ def build(cell: Cell, seed: int):
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    cfg, serve = cell.cfg, cell.traffic["serve"]
+    serve = cell.traffic["serve"]
     dep, pool, order, seed_arrivals = inputs(cell, seed)
     sched = ServingScheduler(
-        model.artifact(dep), spec=serve["spec"], kernel=serve["kernel"],
+        cell.module.artifact(dep), spec=serve["spec"], kernel=serve["kernel"],
         workers=serve["workers"], max_batch=serve["max_batch"],
         max_wait_us=serve["max_wait_us"],
         latency_mode=bool(serve["latency_mode"]))
     try:
-        _warm(sched, pool, cfg["n_in"])
+        _warm(sched, pool)
     except BaseException:
         sched.close()
         raise
@@ -233,7 +244,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     from repro.core.lowering import get_cache
     from repro.telemetry import trace as ttrace
     compiles = _compile_counter()
-    cfg = cell.cfg
     latency = bool(cell.traffic["serve"]["latency_mode"])
     dep, pool, order, seed_arrivals, sched = build(cell, seed)
     try:
@@ -269,13 +279,13 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     del sched
     get_cache().clear()
 
-    want_label, want_steps = reference.answers(dep, pool, latency)
+    mod = cell.module
+    want_label, want_steps = mod.answers(dep, pool, latency)
     img = records.image
     ok = (~records.error & (records.label == want_label[img])
           & (records.steps == want_steps[img]))
-    times = reference.encode(pool, cfg["T"], cfg["x_min"])
     result = Run(cell, seconds, t0, setup_s, records, ok,
-                 work.events(times, cfg["T"], cfg["e_max"]), stats, peak)
+                 mod.events(dep, pool), mod.widths(cell.cfg), stats, peak)
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs), "memory_peak_bytes": memory_peak}
     breakdown = None

@@ -1,5 +1,6 @@
-"""Wall time per batch of the program's ``lane.encode`` span, in ms: TTFS
-encode of the padded buffer, through its read back to the host."""
+"""Wall time per batch of the program's ``lane.encode`` span, in ms: the
+``jax.device_put`` of the padded image buffer to the chip. The TTFS encode
+itself runs on the device, inside the event program's call."""
 
 from benchmarks.chip.metrics._spans import ms_per_batch
 

@@ -8,10 +8,9 @@ from benchmarks.chip import work
 
 
 def read(run):
-    c = run.cell.cfg
     rows = run.in_window() & run.correct_rows
     if not rows.any():
         return None
     ops = float(np.sum(work.ops_per_image(run.events[run.records.image[rows]],
-                                          c["n_out"], c["T"])))
+                                          run.widths, run.cell.cfg["T"])))
     return 100.0 * ops / run.seconds / run.peak["int8_ops_per_s"]

@@ -10,6 +10,27 @@ over the training images times a scale; of the configuration's
 (quantile, scale) pairs the one with the best training accuracy wins. The
 weights and thresholds are the benchmark's own integers: the reference reads
 them from here, never from the program.
+
+This is the first deployment module. A configuration file names its module
+under ``"module"`` (a module of ``benchmarks/chip/``; ``model`` when the key
+is absent), and the harness reaches the deployment only through these five
+functions of it:
+
+  * ``build(cfg, seed) -> dep``: the deployment from the seed; ``dep`` has
+    ``train_accuracy``;
+  * ``artifact(dep) -> Artifact``: the program's deployment artifact;
+  * ``answers(dep, images, latency_mode, control=False) -> (labels, steps)``:
+    the plain reference's label and step count of every image; with
+    ``control=True``, the control's (one precision step below the stated
+    one) in its place;
+  * ``events(dep, images) -> (B, L)`` ints: the events each image feeds
+    into each of the ``L`` layers on the event path;
+  * ``widths(cfg) -> [(n_in, n_out), ...]``: one pair per layer, real
+    neurons only.
+
+A new configuration joins with its config file, its module and the module's
+own plain reference, and edits no file the benchmark has. This module's
+reference is ``reference.py``: one TTFS layer, ``L = 1``.
 """
 
 from __future__ import annotations
@@ -22,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.chip import data, reference
+from benchmarks.chip import data, reference, work
 
 ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.999, 1e-8
 
@@ -132,3 +153,22 @@ def artifact(dep: Deployment):
               **codesign.blocked_layout(dep.w_int8, dep.thresholds, gids,
                                         report.lane)}
     return Artifact(meta, arrays)
+
+
+def answers(dep: Deployment, images: np.ndarray, latency_mode: bool,
+            control: bool = False) -> tuple[np.ndarray, np.ndarray]:
+    """The reference's (label, step count) of every image; with ``control``,
+    the reference's at int4 weights."""
+    weights = reference.int4_weights(dep.w_int8) if control else None
+    return reference.answers(dep, images, latency_mode, weights=weights)
+
+
+def events(dep: Deployment, images: np.ndarray) -> np.ndarray:
+    """(B, 1): the events each image feeds into the one layer."""
+    c = dep.cfg
+    times = reference.encode(images, c["T"], c["x_min"])
+    return work.events(times, c["T"], c["e_max"])[:, None]
+
+
+def widths(cfg: dict) -> list[tuple[int, int]]:
+    return [(cfg["n_in"], cfg["n_out"])]

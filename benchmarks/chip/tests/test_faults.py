@@ -1,6 +1,8 @@
 """A whole run of the harness on the CPU, at a tiny size, with the look for
 a chip skipped: clean, it is correct; with the served path broken underneath
-in each way a serving cell can break, ``correct`` comes out false."""
+in each way a serving cell can break, ``correct`` comes out false. The run
+builds, serves and checks through the deployment module its configuration
+names."""
 
 import json
 import time
@@ -15,9 +17,9 @@ from benchmarks.chip import harness, work
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 
 
-def tiny_cell(latency_mode: bool) -> harness.Cell:
+def tiny_cell(latency_mode: bool, **over) -> harness.Cell:
     cfg = json.loads((CONFIGS / "ttfs-784x150.json").read_text())
-    cfg.update(n_out=30, per_group=3, train_images=256, train_steps=8)
+    cfg.update(n_out=30, per_group=3, train_images=256, train_steps=8, **over)
     load = ({"kind": "poisson", "rate_per_s": 400} if latency_mode
             else {"kind": "closed", "outstanding": 32})
     traffic = {"pool_images": 128, "load": load,
@@ -34,8 +36,8 @@ def run(monkeypatch):
     monkeypatch.setattr(harness, "find_chips", lambda chips: (
         jax.devices("cpu")[:chips], work.peak_for("TPU v5 lite")))
 
-    def go(latency_mode=False):
-        cell = tiny_cell(latency_mode)
+    def go(latency_mode=False, **over):
+        cell = tiny_cell(latency_mode, **over)
         monkeypatch.setattr(harness, "load_cell", lambda w, t: cell)
         out = harness.run("tiny", 2**31 + 9, 0.5, False, time.perf_counter())
         assert out["attempted"] > 0
@@ -51,6 +53,22 @@ def test_clean_run_is_correct(run, latency_mode):
     assert out["correct"] and out["failed"] == 0
     assert list(out)[-1] == "checks"
     assert all(c["value"] == 0 for c in out["checks"].values())
+
+
+def test_run_goes_through_the_configs_module(run, stand_in):
+    mod = stand_in()
+    out = run(module="stand_in")
+    assert out["correct"]
+    assert sorted(set(mod.calls)) == ["answers", "artifact", "build",
+                                      "events", "widths"]
+
+
+def test_correct_is_the_named_modules_reference(run, stand_in):
+    """A module whose reference answers otherwise fails every request."""
+    stand_in(lie=True)
+    out = run(module="stand_in")
+    assert not out["correct"]
+    assert out["checks"]["wrong_labels"]["value"] == out["attempted"]
 
 
 def _ops():

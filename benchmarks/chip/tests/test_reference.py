@@ -1,5 +1,6 @@
 """The plain reference agrees with the program's own integer reference on
-the benchmark's deployments, and its control (int4 weights) does not."""
+the benchmark's deployments, and its control (int4 weights) does not; the
+deployment module's ``answers`` gives the same as the reference it wraps."""
 
 import json
 from pathlib import Path
@@ -33,6 +34,9 @@ def test_reference_matches_the_program_reference(deployment):
         out = SNNReference(model.artifact(deployment)).forward(x)
         assert np.array_equal(labels, np.asarray(out.labels))
         assert np.all(steps == deployment.cfg["T"])
+        m_labels, m_steps = model.answers(deployment, x, latency_mode=False)
+        assert np.array_equal(m_labels, np.asarray(out.labels))
+        assert np.array_equal(m_steps, steps)
     finally:
         install(prev)
 
@@ -49,6 +53,9 @@ def test_latency_steps_follow_the_first_spike(deployment):
         out = rt.forward(x, latency_mode=True)
         assert np.array_equal(labels, np.asarray(out.labels))
         assert np.array_equal(steps, np.asarray(out.steps))
+        m_labels, m_steps = model.answers(deployment, x, latency_mode=True)
+        assert np.array_equal(m_labels, np.asarray(out.labels))
+        assert np.array_equal(m_steps, np.asarray(out.steps))
     finally:
         install(prev)
 
@@ -57,6 +64,8 @@ def test_overflow_rows_take_all_steps(deployment):
     cfg = deployment.cfg
     x = np.ones((2, cfg["n_in"]), np.float32)   # every pixel at step 0
     _, steps = reference.answers(deployment, x, latency_mode=True)
+    assert np.all(steps == cfg["T"])
+    _, steps = model.answers(deployment, x, latency_mode=True)
     assert np.all(steps == cfg["T"])
 
 
@@ -72,6 +81,10 @@ def test_int4_control_is_not_correct(deployment, seed):
         weights=reference.int4_weights(deployment.w_int8))
     assert np.sum(got != want) > 0
     assert np.sum(got_steps != want_steps) > 0
+    m_got, m_got_steps = model.answers(deployment, x, latency_mode=True,
+                                       control=True)
+    assert np.array_equal(m_got, got)
+    assert np.array_equal(m_got_steps, got_steps)
 
 
 def test_int4_weights_have_sixteen_levels():

@@ -23,12 +23,13 @@ class Span:
     cpu_ns: int | None = None
 
 
-def served_batch(trace: str, t: float, pack_ms: float = 5.0,
+def served_batch(trace: str, t: float, wait_ms: float = 3.0,
                  reroute: bool = False) -> list[Span]:
-    """One batch's spans from ``t`` ns on, each phase on CPU half its wall
-    time: idle 10 ms, form 2, pad 1, encode 4, pack ``pack_ms``, dispatch 2,
-    device wait 3, readback 2, the dense reroute 6 (its own forward and
-    dispatch 5 inside it), complete 1."""
+    """One batch's spans from ``t`` ns on, as the lane records a batch whose
+    encode and pack run on the device, each phase on CPU half its wall
+    time: idle 10 ms, form 2, pad 1, encode (the buffer's put) 4, dispatch
+    2, device wait ``wait_ms``, readback 2, the dense reroute 6 (its own
+    forward and dispatch 5 inside it), complete 1."""
     spans: list[Span] = []
 
     def add(name, parent, ms, at=None):
@@ -44,11 +45,10 @@ def served_batch(trace: str, t: float, pack_ms: float = 5.0,
     pad = add("lane.pad", batch.sid, 1)
     runtime = add("runtime", batch.sid, 0, at=pad.wall_ns_end)
     t = pad.wall_ns_end
-    for name, ms in (("lane.encode", 4), ("lane.pack", pack_ms)):
-        t = add(name, runtime.sid, ms).wall_ns_end
+    t = add("lane.encode", runtime.sid, 4).wall_ns_end
     fwd = add("accel.forward", runtime.sid, 2)
     t = add("accel.dispatch", fwd.sid, 2).wall_ns_end
-    for name, ms in (("lane.device_wait", 3), ("lane.readback", 2)):
+    for name, ms in (("lane.device_wait", wait_ms), ("lane.readback", 2)):
         t = add(name, runtime.sid, ms).wall_ns_end
     if reroute:
         rr = add("lane.reroute", runtime.sid, 6)
@@ -65,22 +65,22 @@ def served_batch(trace: str, t: float, pack_ms: float = 5.0,
 def make_run(spans, t0=1.0, seconds=1.0, dropped=0, trace=None, stats=None):
     return harness.Run(cell=None, seconds=seconds, t0=t0, setup_s=0.0,
                        records=None, correct_rows=None, events=None,
-                       stats=stats or {"batches": 0}, peak={}, spans=spans,
-                       spans_dropped=dropped, trace=trace, offset_ns=0.0)
+                       widths=None, stats=stats or {"batches": 0}, peak={},
+                       spans=spans, spans_dropped=dropped, trace=trace,
+                       offset_ns=0.0)
 
 
-# two batches in the window [1 s, 2 s], the second with a 60-ms pack and the
-# dense reroute; a third opens after the window and is left out
+# two batches in the window [1 s, 2 s], the second with a 60-ms device wait
+# and the dense reroute; a third opens after the window and is left out
 SPANS = (served_batch("batch-000000", 1.1e9)
-         + served_batch("batch-000001", 1.5e9, pack_ms=60.0, reroute=True)
-         + served_batch("batch-000002", 2.5e9, pack_ms=1000.0))
+         + served_batch("batch-000001", 1.5e9, wait_ms=60.0, reroute=True)
+         + served_batch("batch-000002", 2.5e9, wait_ms=1000.0))
 
 KNOWN = {
     "pad_ms_per_batch": 1.0,
     "encode_ms_per_batch": 4.0,
-    "pack_ms_per_batch": (5.0 + 60.0) / 2,
     "dispatch_ms_per_batch": 2.0,            # the dense one is in the reroute
-    "device_wait_ms_per_batch": 3.0,
+    "device_wait_ms_per_batch": (3.0 + 60.0) / 2,
     "readback_ms_per_batch": (2.0 + 2.0 + 6.0) / 2,
     "complete_ms_per_batch": 1.0,
     "form_wait_ms": 2.0,
@@ -96,9 +96,9 @@ def test_reader_gives_the_known_value(name):
 
 
 def test_phases_add_up_to_the_batch():
-    """The six phases a batch's host and device-call time is made of sum to
+    """The five phases a batch's host and device-call time is made of sum to
     the batch span's pad-to-runtime-end extent in this synthetic run."""
-    parts = ("pad", "encode", "pack", "dispatch", "device_wait", "readback")
+    parts = ("pad", "encode", "dispatch", "device_wait", "readback")
     total = sum(harness.reader(f"{p}_ms_per_batch")(make_run(SPANS))
                 for p in parts)
     batches = [s for s in SPANS if s.name == "batch"][:2]

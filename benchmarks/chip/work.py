@@ -1,12 +1,14 @@
 """The work the algorithm requires, counted the same whatever implements it,
 and the chip's peaks (``peaks.json``, keyed by JAX's ``device_kind``).
 
-Per served image, full T:
-  * operations: one int32 add per (event, output neuron), plus
-    ``LIF_OPS`` per (step, output neuron): leak shift, subtract, add the
-    step's current, compare with the threshold, latch the first spike;
-  * bytes per kernel call: the int8 weight block once (n_in x n_out, real
-    neurons only), 4 bytes per real event id, 4 bytes per label out.
+Per served image, full T, summed over the layers ``widths`` lists, each
+``(n_in, n_out)``:
+  * operations: one int32 add per (event into the layer, output neuron),
+    plus ``LIF_OPS`` per (step, output neuron): leak shift, subtract, add
+    the step's current, compare with the threshold, latch the first spike;
+  * bytes per call of every layer: each layer's int8 weight block once
+    (n_in x n_out, real neurons only), 4 bytes per real event id, 4 bytes
+    per label out.
 Padded event slots, padded lanes and a dense layer's T x n_in x n_out
 multiply-adds do not count.
 """
@@ -44,12 +46,15 @@ def events(times: np.ndarray, T: int, e_max: int) -> np.ndarray:
     return np.minimum(reference.step_counts(times, T), e_max).sum(axis=1)
 
 
-def ops_per_image(n_events, n_out: int, T: int):
-    return n_events * n_out + LIF_OPS * T * n_out
+def ops_per_image(n_events, widths, T: int):
+    """(..., L) events into each layer -> (...) operations."""
+    return sum(n_events[..., i] * n_out + LIF_OPS * T * n_out
+               for i, (_, n_out) in enumerate(widths))
 
 
-def bytes_per_call(n_in: int, n_out: int, n_events: int, rows: int) -> int:
-    return n_in * n_out + 4 * n_events + 4 * rows
+def bytes_per_call(widths, n_events: int, rows: int) -> int:
+    return (sum(n_in * n_out for n_in, n_out in widths)
+            + 4 * n_events + 4 * rows)
 
 
 def roofline(kernel_s: float, ops: float, nbytes: float, peak: dict
